@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _MESH: Optional[Mesh] = None
 
@@ -62,9 +62,4 @@ def constrain(x: jax.Array, *roles) -> jax.Array:
             spec.append(None)
     # NamedSharding (not bare PartitionSpec) so tracing works outside a
     # `with mesh:` context (e.g. Trainer steps traced at first call).
-    from jax.sharding import NamedSharding
-    try:
-        sh = NamedSharding(mesh, P(*spec))
-    except TypeError:        # AbstractMesh in unit tests
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    return jax.lax.with_sharding_constraint(x, sh)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))

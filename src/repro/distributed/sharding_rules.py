@@ -19,22 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AxisName = Optional[Any]    # None | str | tuple[str, ...]
 
 
-def abstract_mesh(axis_sizes: Sequence[int],
-                  axis_names: Sequence[str]) -> Any:
-    """Construct an ``AbstractMesh`` across JAX versions.
-
-    The constructor signature changed twice upstream: old releases took
-    ``(axis_sizes, axis_names)``, current ones take a single
-    ``shape_tuple`` of ``(name, size)`` pairs.  Tests and dry-run tooling
-    should build meshes through this helper only.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
 @dataclasses.dataclass
 class ShardingRules:
     """logical axis name -> mesh axis (or tuple of mesh axes, or None)."""

@@ -1,10 +1,12 @@
 """Single-token GQA decode attention against a (possibly ring) KV cache.
 
-Grid (batch, kv_heads, kv_blocks): each step loads one (block_s, D) KV tile
-into VMEM and updates an online-softmax accumulator for the g query heads
-sharing that kv head.  `lengths` rides in SMEM (scalar per batch row) and
-masks the tail block; a local `window` restricts attention to the last W
-positions (ring caches pass window=0 and a clamped `lengths`).
+Grid (batch, kv_blocks): each step loads one (block_s, K, D) KV tile — all
+K kv heads at once, so the tile's last two dims are the cache's full
+(K, D) as the TPU lowering requires — into VMEM, and updates one
+online-softmax accumulator per kv head for the g query heads sharing it.
+`lengths` rides in SMEM (scalar per batch row) and masks the tail block; a
+local `window` restricts attention to the last W positions (ring caches
+pass window=0 and a clamped `lengths`).
 
 Oracle: ``repro.kernels.ref.decode_attention``.
 """
@@ -22,8 +24,8 @@ NEG_INF = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            scale, softcap, window, block_s, ns, g):
-    js = pl.program_id(2)
+            scale, softcap, window, block_s, ns, n_kv, g):
+    js = pl.program_id(1)
 
     @pl.when(js == 0)
     def _init():
@@ -39,30 +41,30 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(relevant)
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # (g, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)             # (bs, Dv)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, bs)
-        if softcap and softcap > 0.0:
-            s = jnp.tanh(s / softcap) * softcap
         pos = s_lo + jax.lax.broadcasted_iota(jnp.int32, (g, block_s), 1)
         mask = pos < length
         if window and window > 0:
             mask = mask & (pos >= length - window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[:, 0] = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(p, v)
-        m_ref[:, 0] = m_cur
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32) * scale       # (g, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)         # (bs, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)         # (bs, Dv)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g,bs)
+            if softcap and softcap > 0.0:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[h]                                 # (g, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot(p, v)
+            m_ref[h] = m_cur
 
     @pl.when(js == ns - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, softcap=0.0,
@@ -80,23 +82,24 @@ def decode_attention(q, k_cache, v_cache, lengths, *, softcap=0.0,
 
     qr = q.reshape(B, K, g, D)
     kernel = functools.partial(_kernel, scale=scale, softcap=softcap,
-                               window=window, block_s=bs, ns=ns, g=g)
+                               window=window, block_s=bs, ns=ns, n_kv=K, g=g)
     out = pl.pallas_call(
         kernel,
-        grid=(B, K, ns),
+        grid=(B, ns),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # lengths, whole array
-            pl.BlockSpec((1, 1, g, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bs, 1, Dv), lambda b, h, j: (b, j, h, 0)),
+            pl.BlockSpec((1, K, g, D), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, K, D), lambda b, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, bs, K, Dv), lambda b, j: (b, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, Dv), lambda b, h, j: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, K, g, Dv), lambda b, j: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, g, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g, Dv), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((K, g, Dv), jnp.float32),
+            pltpu.VMEM((K, g, 1), jnp.float32),
+            pltpu.VMEM((K, g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qr, k_cache, v_cache)
     return out.reshape(B, H, Dv)

@@ -3,7 +3,9 @@
 Online-softmax tiling: grid (batch, q_heads, q_blocks, kv_blocks) with the
 kv dimension innermost (sequential on TPU), fp32 accumulator + running
 max/sum in VMEM scratch.  Block sizes default to (128, 128) — MXU-aligned —
-and q/k/v tiles stream HBM->VMEM per BlockSpec.  Irrelevant kv blocks
+and q/k/v tiles stream HBM->VMEM per BlockSpec.  The wrapper moves heads
+ahead of the sequence, so each tile's last two dims are (block, D): the
+TPU lowering needs them tile-aligned or full.  Irrelevant kv blocks
 (beyond the causal frontier or before the local window) are skipped with
 ``pl.when`` so a local-window pass does O(S*W) work, not O(S^2).
 
@@ -44,9 +46,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(relevant)
     def _update():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale     # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)             # (bk, Dv)
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)                   # (bk, Dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq,bk)
         if softcap and softcap > 0.0:
             s = jnp.tanh(s / softcap) * softcap
@@ -61,20 +63,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             mask = mask & (kpos > qpos - window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
+        m_prev = m_ref[...]                                   # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
         alpha = jnp.exp(m_prev - m_cur)
-        l_ref[:, 0] = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jax.lax.dot(p, v))
-        m_ref[:, 0] = m_cur
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
+        m_ref[...] = m_cur
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
@@ -97,20 +97,23 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         _kernel, scale=scale, causal=causal, window=window, softcap=softcap,
         q_offset=q_offset, block_q=bq, block_k=bk, nk=nk, kv_len=T)
 
-    return pl.pallas_call(
+    heads_first = lambda a: jnp.swapaxes(a, 1, 2)      # (B,S,H,D)->(B,H,S,D)
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, Dv), lambda b, h, i, j: (b, j, h // g, 0)),
+            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j: (b, h // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Dv), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+        name="flash_attention",
+    )(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(out)

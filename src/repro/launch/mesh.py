@@ -8,17 +8,24 @@ DCN, 'data'/'model' stay on ICI.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: GSPMD propagates shardings from the constraints the model
+    # code sets (``jax.make_mesh`` defaults to Explicit axes otherwise)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over however many local devices exist (tests/examples)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 # v5e-like hardware constants used by the roofline analysis (per chip).

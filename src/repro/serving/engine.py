@@ -4,7 +4,9 @@ Real JAX execution (any local device count) + a virtual clock for the
 network/queue components we cannot measure on CPU:
 
   * each *row* models one data-parallel replica group: it owns params, a
-    slotted decode cache, and a virtual busy-until time;
+    slotted decode cache, and a virtual busy-until time, all on the row's
+    device (rows go round-robin over the local devices, so with one chip
+    per row a migration really crosses chips);
   * requests route through ``SessionRouter`` (affinity vs baselines);
   * a routed turn whose session state lives on another row pays a
     migration: real `read_slot`/`write_slot` tensor movement + virtual
@@ -109,11 +111,16 @@ class _TurnPlan:
 
 class Row:
     def __init__(self, model: Model, params: Any, max_slots: int,
-                 max_seq: int, profile: HardwareProfile = UNIFORM):
+                 max_seq: int, device: jax.Device,
+                 profile: HardwareProfile = UNIFORM):
         self.model = model
-        self.params = params
-        self.cache = model.init_cache(max_slots, max_seq)
-        self.lengths = jnp.zeros((max_slots,), jnp.int32)
+        self.device = device
+        # a row's weights and state live on its own device; params already
+        # there are not copied, so rows sharing a device share weights
+        self.params = jax.device_put(params, device)
+        with jax.default_device(device):
+            self.cache = model.init_cache(max_slots, max_seq)
+            self.lengths = jnp.zeros((max_slots,), jnp.int32)
         self.active = np.zeros((max_slots,), bool)
         self.slot_sid: List[Optional[str]] = [None] * max_slots
         self.busy_until = 0.0
@@ -148,7 +155,8 @@ class ServingEngine:
                  row_profiles: Optional[Sequence[HardwareProfile]] = None,
                  tracer: Optional[Any] = None,
                  retry: Optional[RetryPolicy] = None,
-                 checkpoint_every: Optional[int] = None):
+                 checkpoint_every: Optional[int] = None,
+                 devices: Optional[Sequence[jax.Device]] = None):
         self.model = model
         # optional repro.runtime.tracing.TraceRecorder: every turn becomes
         # one completed trace (queueing/migration/prefill/decode spans
@@ -157,8 +165,11 @@ class ServingEngine:
         self.tracer = tracer
         profs = list(row_profiles or [])
         profs += [UNIFORM] * (n_rows - len(profs))
+        # rows round-robin over the devices (default: every local device)
+        devs = list(devices or jax.local_devices())
         self.rows = [Row(model, params, max_slots, max_seq,
-                         profile=profs[i]) for i in range(n_rows)]
+                         devs[i % len(devs)], profile=profs[i])
+                     for i in range(n_rows)]
         self.router = SessionRouter(n_rows, policy=policy, seed=seed)
         self.adapters = AdapterStore(n_rows)
         self.net = net
@@ -193,15 +204,15 @@ class ServingEngine:
             lambda p, c, t, l: model.decode_step(p, c, t, l,
                                                  return_hidden=True))
         self._prefill = jax.jit(model.prefill)
-        self._svc = self._calibrate(params)
+        self._svc = self._calibrate()
 
     # -- calibration -----------------------------------------------------------
 
-    def _calibrate(self, params) -> Dict[str, float]:
+    def _calibrate(self) -> Dict[str, float]:
         B = len(self.rows[0].active)
         tok = jnp.zeros((B,), jnp.int32)
         lens = jnp.zeros((B,), jnp.int32)
-        cache = self.rows[0].cache
+        params, cache = self.rows[0].params, self.rows[0].cache
         out = self._decode(params, cache, tok, lens)
         jax.block_until_ready(out[0])
         t0 = time.perf_counter()
@@ -471,7 +482,8 @@ class ServingEngine:
         if s.row is not None and s.row != row_idx:
             # migrate session state between rows: real tensor movement
             src = self.rows[s.row]
-            payload = kvc.read_slot(src.cache, s.slot)
+            payload = jax.device_put(kvc.read_slot(src.cache, s.slot),
+                                     row.device)
             src.cache = kvc.clear_slot(src.cache, s.slot)
             src.active[s.slot] = False
             src.slot_sid[s.slot] = None
@@ -493,7 +505,8 @@ class ServingEngine:
         if plan.recovery is not None:
             # real state reconstruction, exactly as priced
             if plan.recovery == "ckpt":
-                row.cache = kvc.write_slot(row.cache, s.ckpt, slot)
+                row.cache = kvc.write_slot(
+                    row.cache, jax.device_put(s.ckpt, row.device), slot)
                 row.lengths = row.lengths.at[slot].set(s.ckpt_len)
                 replay = s.transcript[s.ckpt_len:]
                 self.recoveries_ckpt += 1

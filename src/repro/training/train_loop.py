@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs.shapes import ShapeConfig
 from repro.launch import steps as steplib
+from repro.launch.mesh import make_local_mesh
 from repro.models.common import ModelConfig
 from . import checkpointing as ckpt
 from .data import DataConfig, TokenPipeline
@@ -42,7 +43,7 @@ class Trainer:
         self.model_cfg = model_cfg
         self.shape = shape
         self.tc = train_cfg
-        self.mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh or make_local_mesh()
         self.bundle = steplib.make_train_step(model_cfg, shape, self.mesh,
                                               ocfg=ocfg)
         model = self.bundle.meta["model"]

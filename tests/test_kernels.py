@@ -148,11 +148,6 @@ def test_ssd_vs_sequential_decode(rng):
     np.testing.assert_allclose(s_chunk, state, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.xfail(
-    reason="known Pallas interpret-mode failure on current jax (seed "
-           "baseline); tracked in ROADMAP — in-tree marker replaces the "
-           "former CI-only --deselect so tier-1 passes without flags",
-    strict=False)
 @pytest.mark.parametrize("B,S,W,bs,bw", [
     (1, 16, 8, 4, 8),
     (2, 32, 24, 8, 8),

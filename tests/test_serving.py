@@ -1,4 +1,10 @@
 """Serving engine: session/KV affinity (paper §7.2 applied)."""
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -225,3 +231,27 @@ def test_turn_traces_decompose_to_e2e(model_and_params):
         sp = next(s for s in tr.spans if s.cat == "migration")
         assert sp.name == "session_migrate" and sp.args["bytes"] > 0
     assert totals["migration"] > 0.0
+
+
+def test_one_row_per_device_serves_the_same_tokens():
+    """One row per device (four virtual CPU devices, in a child process
+    that owns them) serves the same tokens as all rows on one device, and
+    random routing moves sessions across devices: the CPU rehearsal of
+    ``chip_smoke.py --four-chips``."""
+    root = Path(__file__).resolve().parents[1]
+    code = textwrap.dedent("""
+        import jax, chip_smoke
+        from repro import configs
+        assert len(jax.devices()) == 4, jax.devices()
+        model, params = chip_smoke.build(configs.get_smoke("granite-3-2b"), 0)
+        chip_smoke.four_chip_phase(model, params, jax.devices(), seed=0,
+                                   max_seq=64, n_sessions=8, n_turns=2,
+                                   prompt_lens=(4, 12))
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]))
+    run = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stdout[-2000:] + run.stderr[-4000:]
+    assert "random: 16/16 turns token-identical" in run.stdout

@@ -3,14 +3,14 @@ devices needed)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro import configs
 from repro.distributed import sharding_rules as sr
 from repro.models import build_model
 
-MESH = sr.abstract_mesh((16, 16), ("data", "model"))
-MESH3 = sr.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_divisible_axis_sharded():

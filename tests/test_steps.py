@@ -9,12 +9,13 @@ import pytest
 from repro import configs
 from repro.configs.shapes import ShapeConfig
 from repro.launch import steps as steplib
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_local_mesh()
 
 
 def _jit(mesh, bundle):
