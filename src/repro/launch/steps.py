@@ -235,7 +235,8 @@ def make_serve_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
 
     def serve_step(params, cache, tokens, lengths):
         logits, new_cache = model.decode_step(params, cache, tokens, lengths)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, new_cache
 
     return StepBundle(

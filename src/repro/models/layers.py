@@ -81,19 +81,21 @@ def init_embedding(pf: ParamFactory, cfg: ModelConfig):
 
 
 def embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
-    emb = jnp.take(params["tok_embed"], tokens, axis=0)
-    return emb.astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        emb = jnp.take(params["tok_embed"], tokens, axis=0)
+        return emb.astype(cfg.compute_dtype)
 
 
 def unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("...d,vd->...v", x, params["tok_embed"])
-    else:
-        logits = jnp.einsum("...d,dv->...v", x, params["unembed"])
-    if cfg.logit_softcap:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return logits.astype(jnp.float32)
+    with jax.named_scope("unembed"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("...d,vd->...v", x, params["tok_embed"])
+        else:
+            logits = jnp.einsum("...d,dv->...v", x, params["unembed"])
+        if cfg.logit_softcap:
+            logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
+        return logits.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -143,17 +145,23 @@ def attention_train(p: Params, cfg: ModelConfig, x: jax.Array,
 def attention_prefill(p: Params, cfg: ModelConfig, x: jax.Array,
                       window: int = 0) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     B, S, _ = x.shape
-    h = rmsnorm(p["ln"], x, cfg.norm_eps)
-    q, k, v = _qkv(p, cfg, h)
-    pos = jnp.arange(S)[None]
-    q = rope(q, jnp.broadcast_to(pos, (B, S)), cfg.rope_theta)
-    k = rope(k, jnp.broadcast_to(pos, (B, S)), cfg.rope_theta)
-    q = shard_attn_q(cfg, q)
-    o = ops.mha(q, k, v, causal=True, window=window,
-                q_chunk=cfg.attn_chunk, unroll=cfg.unroll_inner)
-    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.compute_dtype))
-    cache = {"k": k, "v": v}
-    return x + out, cache
+    with jax.named_scope("qkv"):
+        h = rmsnorm(p["ln"], x, cfg.norm_eps)
+        q, k, v = _qkv(p, cfg, h)
+        pos = jnp.arange(S)[None]
+        q = rope(q, jnp.broadcast_to(pos, (B, S)), cfg.rope_theta)
+        k = rope(k, jnp.broadcast_to(pos, (B, S)), cfg.rope_theta)
+        q = shard_attn_q(cfg, q)
+    with jax.named_scope("attn_kernel"):
+        o = ops.mha(q, k, v, causal=True, window=window,
+                    q_chunk=cfg.attn_chunk, unroll=cfg.unroll_inner)
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bshk,hkd->bsd", o,
+                         p["wo"].astype(cfg.compute_dtype))
+        x = x + out
+    with jax.named_scope("kv_write"):
+        cache = {"k": k, "v": v}
+    return x, cache
 
 
 def attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
@@ -161,17 +169,25 @@ def attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
                      window: int = 0) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x: (B, d) one token per row; cache k/v: (B, Smax, K, Dh)."""
     B, _ = x.shape
-    h = rmsnorm(p["ln"], x[:, None, :], cfg.norm_eps)
-    q, k, v = _qkv(p, cfg, h)                       # (B,1,H,Dh)/(B,1,K,Dh)
-    q = rope(q, lengths[:, None], cfg.rope_theta)[:, 0]      # (B,H,Dh)
-    k = rope(k, lengths[:, None], cfg.rope_theta)[:, 0]      # (B,K,Dh)
-    v = v[:, 0]
-    bidx = jnp.arange(B)
-    k_cache = cache["k"].at[bidx, lengths].set(k.astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, lengths].set(v.astype(cache["v"].dtype))
-    o = ops.decode_attention(q, k_cache, v_cache, lengths + 1, window=window)
-    out = jnp.einsum("bhk,hkd->bd", o, p["wo"].astype(cfg.compute_dtype))
-    return x + out, {"k": k_cache, "v": v_cache}
+    with jax.named_scope("qkv"):
+        h = rmsnorm(p["ln"], x[:, None, :], cfg.norm_eps)
+        q, k, v = _qkv(p, cfg, h)                   # (B,1,H,Dh)/(B,1,K,Dh)
+        q = rope(q, lengths[:, None], cfg.rope_theta)[:, 0]  # (B,H,Dh)
+        k = rope(k, lengths[:, None], cfg.rope_theta)[:, 0]  # (B,K,Dh)
+        v = v[:, 0]
+    with jax.named_scope("kv_write"):
+        bidx = jnp.arange(B)
+        k_cache = cache["k"].at[bidx, lengths].set(
+            k.astype(cache["k"].dtype))
+        v_cache = cache["v"].at[bidx, lengths].set(
+            v.astype(cache["v"].dtype))
+    with jax.named_scope("attn_kernel"):
+        o = ops.decode_attention(q, k_cache, v_cache, lengths + 1,
+                                 window=window)
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bhk,hkd->bd", o, p["wo"].astype(cfg.compute_dtype))
+        x = x + out
+    return x, {"k": k_cache, "v": v_cache}
 
 
 def attention_cache_spec(cfg: ModelConfig, batch: int, max_seq: int,

@@ -79,53 +79,63 @@ def _block_train(bp: Params, cfg: ModelConfig, x: jax.Array, kind: str):
     return x
 
 
+def _mixer_scope(cfg: ModelConfig, kind: str) -> str:
+    """The named scope of a block's mixer (listed in PERF.md, Layers)."""
+    if kind == "attn" and cfg.mla:
+        return "mla"
+    return "attn" if kind == "wattn" else kind
+
+
+def _block_mlp(bp, cfg, x, kind):
+    """The block's MLP (an SSD block has none), under its named scope."""
+    routed = cfg.family == "moe" and kind == "attn"
+    with jax.named_scope("moe" if routed else "mlp"):
+        if routed:
+            return moe.moe_block(bp["mlp"], cfg, x)
+        return layers.mlp_block(bp["mlp"], cfg, x)
+
+
 def _block_prefill(bp, cfg, x, kind):
-    if kind == "attn":
-        if cfg.mla:
-            x, cache = mla.mla_prefill(bp["mixer"], cfg, x)
-        else:
+    with jax.named_scope(_mixer_scope(cfg, kind)):
+        if kind == "attn":
+            if cfg.mla:
+                x, cache = mla.mla_prefill(bp["mixer"], cfg, x)
+            else:
+                x, cache = layers.attention_prefill(bp["mixer"], cfg, x)
+        elif kind == "rglru":
+            x, cache = rglru.rglru_prefill(bp["mixer"], cfg, x)
+        elif kind == "ssd":
+            x, cache = ssd.ssd_prefill(bp["mixer"], cfg, x)
+        elif kind == "wattn":
             x, cache = layers.attention_prefill(bp["mixer"], cfg, x)
-        if cfg.family == "moe":
-            x = moe.moe_block(bp["mlp"], cfg, x)
-        else:
-            x = layers.mlp_block(bp["mlp"], cfg, x)
-    elif kind == "rglru":
-        x, cache = rglru.rglru_prefill(bp["mixer"], cfg, x)
-        x = layers.mlp_block(bp["mlp"], cfg, x)
-    elif kind == "ssd":
-        x, cache = ssd.ssd_prefill(bp["mixer"], cfg, x)
-    elif kind == "wattn":
-        x, cache = layers.attention_prefill(bp["mixer"], cfg, x)
-        w = cfg.attn_window
-        cache = {"k": cache["k"][:, -w:], "v": cache["v"][:, -w:]}
-        x = layers.mlp_block(bp["mlp"], cfg, x)
+            w = cfg.attn_window
+            cache = {"k": cache["k"][:, -w:], "v": cache["v"][:, -w:]}
+    if kind != "ssd":
+        x = _block_mlp(bp, cfg, x, kind)
     return x, cache
 
 
 def _block_decode(bp, cfg, x, cache, lengths, kind):
-    if kind == "attn":
-        if cfg.mla:
-            x, cache = mla.mla_decode(bp["mixer"], cfg, x, cache, lengths)
-        else:
-            x, cache = layers.attention_decode(bp["mixer"], cfg, x, cache,
-                                               lengths)
-        if cfg.family == "moe":
-            x = moe.moe_block(bp["mlp"], cfg, x[:, None, :])[:, 0]
-        else:
-            x = layers.mlp_block(bp["mlp"], cfg, x[:, None, :])[:, 0]
-    elif kind == "rglru":
-        x, cache = rglru.rglru_decode(bp["mixer"], cfg, x, cache, lengths)
-        x = layers.mlp_block(bp["mlp"], cfg, x[:, None, :])[:, 0]
-    elif kind == "ssd":
-        x, cache = ssd.ssd_decode(bp["mixer"], cfg, x, cache, lengths)
-    elif kind == "wattn":
-        w = cfg.attn_window
-        ring_len = cache["k"].shape[1]
-        slot = lengths % ring_len
-        valid = jnp.minimum(lengths + 1, ring_len)
-        x, cache = _ring_attention_decode(bp["mixer"], cfg, x, cache, lengths,
-                                          slot, valid)
-        x = layers.mlp_block(bp["mlp"], cfg, x[:, None, :])[:, 0]
+    with jax.named_scope(_mixer_scope(cfg, kind)):
+        if kind == "attn":
+            if cfg.mla:
+                x, cache = mla.mla_decode(bp["mixer"], cfg, x, cache, lengths)
+            else:
+                x, cache = layers.attention_decode(bp["mixer"], cfg, x, cache,
+                                                   lengths)
+        elif kind == "rglru":
+            x, cache = rglru.rglru_decode(bp["mixer"], cfg, x, cache,
+                                          lengths)
+        elif kind == "ssd":
+            x, cache = ssd.ssd_decode(bp["mixer"], cfg, x, cache, lengths)
+        elif kind == "wattn":
+            ring_len = cache["k"].shape[1]
+            slot = lengths % ring_len
+            valid = jnp.minimum(lengths + 1, ring_len)
+            x, cache = _ring_attention_decode(bp["mixer"], cfg, x, cache,
+                                              lengths, slot, valid)
+    if kind != "ssd":
+        x = _block_mlp(bp, cfg, x[:, None, :], kind)[:, 0]
     return x, cache
 
 
@@ -133,17 +143,22 @@ def _ring_attention_decode(p, cfg, x, cache, lengths, slot, valid):
     """Window attention against a ring-buffer cache (slot = pos % window)."""
     from repro.kernels import ops
     B, _ = x.shape
-    h = layers.rmsnorm(p["ln"], x[:, None, :], cfg.norm_eps)
-    q, k, v = layers._qkv(p, cfg, h)
-    q = layers.rope(q, lengths[:, None], cfg.rope_theta)[:, 0]
-    k = layers.rope(k, lengths[:, None], cfg.rope_theta)[:, 0]
-    v = v[:, 0]
-    bidx = jnp.arange(B)
-    k_c = cache["k"].at[bidx, slot].set(k.astype(cache["k"].dtype))
-    v_c = cache["v"].at[bidx, slot].set(v.astype(cache["v"].dtype))
-    o = ops.decode_attention(q, k_c, v_c, valid)
-    out = jnp.einsum("bhk,hkd->bd", o, p["wo"].astype(cfg.compute_dtype))
-    return x + out, {"k": k_c, "v": v_c}
+    with jax.named_scope("qkv"):
+        h = layers.rmsnorm(p["ln"], x[:, None, :], cfg.norm_eps)
+        q, k, v = layers._qkv(p, cfg, h)
+        q = layers.rope(q, lengths[:, None], cfg.rope_theta)[:, 0]
+        k = layers.rope(k, lengths[:, None], cfg.rope_theta)[:, 0]
+        v = v[:, 0]
+    with jax.named_scope("kv_write"):
+        bidx = jnp.arange(B)
+        k_c = cache["k"].at[bidx, slot].set(k.astype(cache["k"].dtype))
+        v_c = cache["v"].at[bidx, slot].set(v.astype(cache["v"].dtype))
+    with jax.named_scope("attn_kernel"):
+        o = ops.decode_attention(q, k_c, v_c, valid)
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bhk,hkd->bd", o, p["wo"].astype(cfg.compute_dtype))
+        x = x + out
+    return x, {"k": k_c, "v": v_c}
 
 
 def _block_cache_spec(cfg, kind, batch, max_seq):
@@ -303,45 +318,8 @@ class Model:
                 x, _ = self._remat(body)(x, _tree_index(params["layers"], li))
         return x
 
-    # -- public entry points --------------------------------------------------
-
-    def forward_train(self, params: Params, batch: Dict[str, jax.Array]):
-        x = self._embed_inputs(params, batch)
-        x = self._apply_stack_train(params, x)
-        return layers.unembed(params["embed"], self.cfg, x)
-
-    def loss(self, params: Params, batch: Dict[str, jax.Array]):
+    def _apply_stack_prefill(self, params: Params, x: jax.Array):
         cfg = self.cfg
-        logits = self.forward_train(params, batch)        # (B,S,V) fp32
-        if cfg.family == "encoder" or not cfg.is_causal:
-            targets = batch["labels"]
-            valid = targets >= 0
-            tgt = jnp.where(valid, targets, 0)
-            nll = self._nll(logits, tgt)
-            loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
-        else:
-            targets = batch["tokens"][:, 1:]
-            nll = self._nll(logits[:, :-1], targets)
-            loss = jnp.mean(nll)
-        return loss, {"loss": loss}
-
-    def _nll(self, logits: jax.Array, targets: jax.Array) -> jax.Array:
-        lp = jax.nn.log_softmax(logits, axis=-1)
-        if self.cfg.onehot_loss:
-            # iota-compare one-hot + contraction: under a vocab-sharded
-            # layout this lowers to a tiny (B,S) partial-sum all-reduce
-            # instead of materializing/gathering the full logits.
-            V = logits.shape[-1]
-            onehot = (targets[..., None]
-                      == jax.lax.broadcasted_iota(jnp.int32, (V,), 0)
-                      ).astype(lp.dtype)
-            return -jnp.einsum("bsv,bsv->bs", lp, onehot)
-        return -jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
-
-    def prefill(self, params: Params, batch: Dict[str, jax.Array]):
-        """Returns (last-position logits, decode cache)."""
-        cfg = self.cfg
-        x = self._embed_inputs(params, batch)
         if self._hybrid:
             caches: Dict[str, Any] = {}
             def group_fn(x, gp):
@@ -374,14 +352,11 @@ class Model:
                     x, c = body(x, _tree_index(params["layers"], li))
                     c_list.append(c)
                 caches = _tree_stack(c_list)
-        logits = layers.unembed(params["embed"], cfg, x[:, -1:])[:, 0]
-        return logits, caches
+        return x, caches
 
-    def decode_step(self, params: Params, cache: Any, tokens: jax.Array,
-                    lengths: jax.Array, return_hidden: bool = False):
-        """tokens (B,) int32, lengths (B,). Returns (logits (B,V), cache)."""
+    def _apply_stack_decode(self, params: Params, cache: Any, x: jax.Array,
+                            lengths: jax.Array):
         cfg = self.cfg
-        x = layers.embed(params["embed"], cfg, tokens)
         if self._hybrid:
             def group_fn(x, xs):
                 gp, gc = xs
@@ -420,7 +395,59 @@ class Model:
                                     _tree_index(cache, li)))
                     c_list.append(c)
                 new_cache = _tree_stack(c_list)
-        logits = layers.unembed(params["embed"], cfg, x[:, None])[:, 0]
+        return x, new_cache
+
+    # -- public entry points --------------------------------------------------
+
+    def forward_train(self, params: Params, batch: Dict[str, jax.Array]):
+        x = self._embed_inputs(params, batch)
+        x = self._apply_stack_train(params, x)
+        return layers.unembed(params["embed"], self.cfg, x)
+
+    def loss(self, params: Params, batch: Dict[str, jax.Array]):
+        cfg = self.cfg
+        logits = self.forward_train(params, batch)        # (B,S,V) fp32
+        if cfg.family == "encoder" or not cfg.is_causal:
+            targets = batch["labels"]
+            valid = targets >= 0
+            tgt = jnp.where(valid, targets, 0)
+            nll = self._nll(logits, tgt)
+            loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
+        else:
+            targets = batch["tokens"][:, 1:]
+            nll = self._nll(logits[:, :-1], targets)
+            loss = jnp.mean(nll)
+        return loss, {"loss": loss}
+
+    def _nll(self, logits: jax.Array, targets: jax.Array) -> jax.Array:
+        lp = jax.nn.log_softmax(logits, axis=-1)
+        if self.cfg.onehot_loss:
+            # iota-compare one-hot + contraction: under a vocab-sharded
+            # layout this lowers to a tiny (B,S) partial-sum all-reduce
+            # instead of materializing/gathering the full logits.
+            V = logits.shape[-1]
+            onehot = (targets[..., None]
+                      == jax.lax.broadcasted_iota(jnp.int32, (V,), 0)
+                      ).astype(lp.dtype)
+            return -jnp.einsum("bsv,bsv->bs", lp, onehot)
+        return -jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
+
+    def prefill(self, params: Params, batch: Dict[str, jax.Array]):
+        """Returns (last-position logits, decode cache)."""
+        x = self._embed_inputs(params, batch)
+        with jax.named_scope("layers"):
+            x, caches = self._apply_stack_prefill(params, x)
+        logits = layers.unembed(params["embed"], self.cfg, x[:, -1:])[:, 0]
+        return logits, caches
+
+    def decode_step(self, params: Params, cache: Any, tokens: jax.Array,
+                    lengths: jax.Array, return_hidden: bool = False):
+        """tokens (B,) int32, lengths (B,). Returns (logits (B,V), cache)."""
+        x = layers.embed(params["embed"], self.cfg, tokens)
+        with jax.named_scope("layers"):
+            x, new_cache = self._apply_stack_decode(params, cache, x,
+                                                    lengths)
+        logits = layers.unembed(params["embed"], self.cfg, x[:, None])[:, 0]
         if return_hidden:
             return logits, new_cache, x
         return logits, new_cache

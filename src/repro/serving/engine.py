@@ -630,7 +630,8 @@ class ServingEngine:
         def sel(o, n):
             m = mask.reshape((1, -1) + (1,) * (o.ndim - 2))
             return jnp.where(m, n.astype(o.dtype), o)
-        return jax.tree_util.tree_map(sel, old_cache, new_cache)
+        with jax.named_scope("commit"):
+            return jax.tree_util.tree_map(sel, old_cache, new_cache)
 
     def _advance(self, row: Row, slot: int, tok: int):
         """Feed one known token into the slot's cache (prefill path)."""

@@ -1,5 +1,7 @@
 """launch.steps bundles execute end-to-end on a local (1,1) mesh."""
+import contextlib
 import dataclasses as dc
+import re
 
 import numpy as np
 import jax
@@ -92,3 +94,79 @@ def test_grad_accum_matches_single_shot(mesh, rng):
         _, metrics = _jit(mesh, bundle)(state, batch)
         losses[accum] = float(metrics["loss"])
     assert losses[1] == pytest.approx(losses[2], rel=1e-5)
+
+
+# -- named scopes: stage names in the compiled steps' metadata ---------------
+
+STEP_SCOPES = {     # arch -> scopes that both steps carry
+    "granite-3-2b": {"embed", "layers", "attn", "qkv", "attn_kernel",
+                     "attn_out", "mlp", "unembed"},
+    "deepseek-v2-236b": {"embed", "layers", "mla", "moe", "unembed"},
+    "recurrentgemma-9b": {"embed", "layers", "rglru", "attn", "qkv",
+                          "attn_kernel", "attn_out", "mlp", "unembed"},
+    "mamba2-780m": {"embed", "layers", "ssd", "unembed"},
+}
+SCOPE_CASES = [("granite-3-2b", False)] + [(a, True) for a in STEP_SCOPES]
+_META = re.compile(r',? metadata=\{(?:[^{}"]|"[^"]*")*\}')
+_FRAMES = re.compile(r'^(FileNames|FunctionNames|FileLocations|StackFrames'
+                     r'|\d+ .*)$')
+
+
+def _step_text(mesh, arch, scan, kind):
+    cfg = dc.replace(configs.get_smoke(arch), scan_layers=scan)
+    if kind == "serve":
+        bundle = steplib.make_serve_step(
+            cfg, ShapeConfig("d", seq_len=32, global_batch=2, kind="decode"),
+            mesh)
+    else:
+        bundle = steplib.make_prefill_step(
+            cfg, ShapeConfig("p", seq_len=16, global_batch=2,
+                             kind="prefill"), mesh)
+    with mesh:
+        return _jit(mesh, bundle).lower(*bundle.input_specs).compile() \
+            .as_text()
+
+
+def _without_metadata(text):
+    """The compiled text without op metadata and source locations, its
+    instructions renamed in order of appearance (the numeric suffixes XLA
+    gives are labels, and scopes can shift them)."""
+    lines = [ln for ln in _META.sub("", text).splitlines()
+             if not _FRAMES.match(ln)]
+    names = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%i{len(names)}"),
+                  "\n".join(lines))
+
+
+@pytest.mark.parametrize("arch,scan", SCOPE_CASES)
+@pytest.mark.parametrize("kind", ["serve", "prefill"])
+def test_steps_name_their_stages(mesh, arch, scan, kind):
+    text = _step_text(mesh, arch, scan, kind)
+    found = {part for op in re.findall(r'op_name="([^"]*)"', text)
+             for part in op.split("/")}
+    want = set(STEP_SCOPES[arch])
+    if kind == "serve":
+        want |= {"sample"} | ({"kv_write"} if "qkv" in want else set())
+    assert want <= found, want - found
+
+
+@pytest.mark.parametrize("arch,scan", SCOPE_CASES)
+@pytest.mark.parametrize("kind", ["serve", "prefill"])
+def test_scopes_leave_the_compiled_steps_unchanged(mesh, monkeypatch, arch,
+                                                   scan, kind):
+    scoped = _step_text(mesh, arch, scan, kind)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _step_text(mesh, arch, scan, kind)
+    assert "layers/" in scoped and "layers/" not in plain
+    assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+def test_engine_commit_is_named():
+    from repro.serving.engine import ServingEngine
+    old = {"k": jnp.zeros((2, 4, 8))}
+    text = jax.jit(ServingEngine._commit).lower(
+        old, old, jnp.array([True, False, True, False])).as_text(
+            debug_info=True)
+    assert "commit/" in text
