@@ -71,17 +71,18 @@ def mha(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
         q_offset=q_offset, interpret=(be == "interpret"))
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *, softcap=0.0, scale=None,
-                     window=0):
+def decode_attention(q, k_cache, v_cache, lengths, *, layer=None,
+                     softcap=0.0, scale=None, window=0):
+    """Caches (B,Smax,K,D), or a layer stack (L,B,Smax,K,D) and ``layer``."""
     be = get_backend()
     if be == "jnp":
-        return ref.decode_attention(q, k_cache, v_cache, lengths,
+        return ref.decode_attention(q, k_cache, v_cache, lengths, layer=layer,
                                     softcap=softcap, scale=scale,
                                     window=window)
     _, da, *_ = _pallas_mod()
     return da.decode_attention(
-        q, k_cache, v_cache, lengths, softcap=softcap, scale=scale,
-        window=window, interpret=(be == "interpret"))
+        q, k_cache, v_cache, lengths, layer=layer, softcap=softcap,
+        scale=scale, window=window, interpret=(be == "interpret"))
 
 
 def ssd(x, dt, A, Bm, Cm, D=None, *, chunk=256, init_state=None,

@@ -116,14 +116,17 @@ def _mha_core(q, k, v, qpos, *, causal, window, softcap, scale):
 
 def decode_attention(
     q: jax.Array,              # (B, H, D)
-    k_cache: jax.Array,        # (B, Smax, K, D)
-    v_cache: jax.Array,        # (B, Smax, K, D)
+    k_cache: jax.Array,        # (B, Smax, K, D), or (L, B, Smax, K, D)
+    v_cache: jax.Array,        # (B, Smax, K, D), or (L, B, Smax, K, D)
     lengths: jax.Array,        # (B,) int32 — valid cache entries per row
     *,
+    layer: Optional[jax.Array] = None,   # the stack's layer to read
     softcap: float = 0.0,
     scale: Optional[float] = None,
     window: int = 0,
 ) -> jax.Array:
+    if layer is not None:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     B, H, D = q.shape
     Smax, K = k_cache.shape[1], k_cache.shape[2]
     g = H // K

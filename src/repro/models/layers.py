@@ -166,8 +166,14 @@ def attention_prefill(p: Params, cfg: ModelConfig, x: jax.Array,
 
 def attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
                      cache: Dict[str, jax.Array], lengths: jax.Array,
+                     layer: Optional[jax.Array] = None,
                      window: int = 0) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """x: (B, d) one token per row; cache k/v: (B, Smax, K, Dh)."""
+    """x: (B, d) one token per row; cache k/v: (B, Smax, K, Dh), or the
+    model's layer stack (L, B, Smax, K, Dh) and this block's ``layer``.
+
+    With the stack, the new position is written into it in place and the
+    kernel reads the layer's blocks from it: no buffer holds one layer's
+    cache apart from the stack."""
     B, _ = x.shape
     with jax.named_scope("qkv"):
         h = rmsnorm(p["ln"], x[:, None, :], cfg.norm_eps)
@@ -176,14 +182,14 @@ def attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
         k = rope(k, lengths[:, None], cfg.rope_theta)[:, 0]  # (B,K,Dh)
         v = v[:, 0]
     with jax.named_scope("kv_write"):
-        bidx = jnp.arange(B)
-        k_cache = cache["k"].at[bidx, lengths].set(
-            k.astype(cache["k"].dtype))
-        v_cache = cache["v"].at[bidx, lengths].set(
-            v.astype(cache["v"].dtype))
+        at = (jnp.arange(B), lengths)
+        if layer is not None:
+            at = (layer,) + at
+        k_cache = cache["k"].at[at].set(k.astype(cache["k"].dtype))
+        v_cache = cache["v"].at[at].set(v.astype(cache["v"].dtype))
     with jax.named_scope("attn_kernel"):
         o = ops.decode_attention(q, k_cache, v_cache, lengths + 1,
-                                 window=window)
+                                 layer=layer, window=window)
     with jax.named_scope("attn_out"):
         out = jnp.einsum("bhk,hkd->bd", o, p["wo"].astype(cfg.compute_dtype))
         x = x + out

@@ -115,14 +115,16 @@ def _block_prefill(bp, cfg, x, kind):
     return x, cache
 
 
-def _block_decode(bp, cfg, x, cache, lengths, kind):
+def _block_decode(bp, cfg, x, cache, lengths, kind, layer=None):
+    """``layer``: ``cache`` is the model's layer stack, read and written at
+    that layer (``attn`` without MLA only)."""
     with jax.named_scope(_mixer_scope(cfg, kind)):
         if kind == "attn":
             if cfg.mla:
                 x, cache = mla.mla_decode(bp["mixer"], cfg, x, cache, lengths)
             else:
                 x, cache = layers.attention_decode(bp["mixer"], cfg, x, cache,
-                                                   lengths)
+                                                   lengths, layer)
         elif kind == "rglru":
             x, cache = rglru.rglru_decode(bp["mixer"], cfg, x, cache,
                                           lengths)
@@ -380,6 +382,25 @@ class Model:
                 x, new_cache["tail"][f"t{i}"] = _block_decode(
                     params["tail"][f"t{i}"], cfg, x, cache["tail"][f"t{i}"],
                     lengths, kind)
+        elif self._kind(0) == "attn" and not cfg.mla:
+            # the stacked cache rides in the carry and each block updates
+            # its one position in place: no layer's cache is sliced out
+            # of the stack and written back
+            def body(carry, xs):
+                x, cache = carry
+                lp, li = xs
+                return _block_decode(lp, cfg, x, cache, lengths, "attn",
+                                     layer=li), None
+            if cfg.scan_layers:
+                (x, new_cache), _ = jax.lax.scan(
+                    body, (x, cache),
+                    (params["layers"], jnp.arange(cfg.n_layers)))
+            else:
+                new_cache = cache
+                for li in range(cfg.n_layers):
+                    (x, new_cache), _ = body(
+                        (x, new_cache), (_tree_index(params["layers"], li),
+                                         li))
         else:
             kind = self._kind(0)
             def body(x, xs):

@@ -72,16 +72,43 @@ def test_flash_attention_mla_vdim(rng):
     (3, 8, 1, 8, 32),      # MQA
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_attention(rng, B, H, K, D, Smax, dtype):
+@pytest.mark.parametrize("n_layers", [0, 3], ids=["cache", "stack"])
+def test_decode_attention(rng, B, H, K, D, Smax, dtype, n_layers):
+    """One layer's cache, or a stack of layers that hold different values
+    read at one layer (so reading another layer fails); KV blocks of 16
+    positions, fewer than ``Smax``."""
     q = _arr(rng, (B, H, D), dtype)
-    kc = _arr(rng, (B, Smax, K, D), dtype)
-    vc = _arr(rng, (B, Smax, K, D), dtype)
+    stack = (n_layers,) if n_layers else ()
+    kc = _arr(rng, stack + (B, Smax, K, D), dtype)
+    vc = _arr(rng, stack + (B, Smax, K, D), dtype)
     lengths = jnp.asarray(rng.integers(1, Smax, (B,)), jnp.int32)
-    want = ref.decode_attention(q, kc, vc, lengths)
-    got = decode_attention(q, kc, vc, lengths, block_s=16, interpret=True)
+    if n_layers:
+        layer = jnp.int32(n_layers - 2)
+        want = ref.decode_attention(q, kc[layer], vc[layer], lengths)
+        np.testing.assert_array_equal(
+            ref.decode_attention(q, kc, vc, lengths, layer=layer), want)
+    else:
+        layer = None
+        want = ref.decode_attention(q, kc, vc, lengths)
+    got = decode_attention(q, kc, vc, lengths, layer=layer, block_s=16,
+                           interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("smax,kv_bytes,want", [
+    (512, 32 * 256 * 2, 512),    # deepseek-7b: 16 MiB of K and V tiles
+    (1024, 32 * 256 * 4, 256),   # the same in f32: 512 would take 32 MiB
+    (2048, 8 * 128 * 2, 512),    # granite-3-2b: capped at MAX_BLOCK_S
+    (48, 2 * 32 * 4, 48),        # all of a short cache
+    (600, 32 * 256 * 2, 200),    # the largest multiple of 8 that divides
+])
+def test_decode_attention_kv_block(smax, kv_bytes, want):
+    from repro.kernels.decode_attention import KV_VMEM_BYTES, kv_block
+    bs = kv_block(smax, kv_bytes)
+    assert bs == want
+    assert smax % bs == 0 and 2 * bs * kv_bytes <= KV_VMEM_BYTES
 
 
 def test_decode_attention_window(rng):

@@ -170,3 +170,88 @@ def test_engine_commit_is_named():
         old, old, jnp.array([True, False, True, False])).as_text(
             debug_info=True)
     assert "commit/" in text
+
+
+# -- the serve step's cache: the one new position written into the stack ----
+
+def _fp32(arch, **kw):
+    return dc.replace(configs.get_smoke(arch), param_dtype=jnp.float32,
+                      compute_dtype=jnp.float32, **kw)
+
+
+def _decode_after_prefill(model, params, toks, max_seq):
+    """Logits of a prefill over ``toks`` and of a decode step that feeds
+    its last token after a prefill over the rest; the cache widened to
+    ``max_seq`` positions (a ring cache keeps its window)."""
+    want, _ = model.prefill(params, {"tokens": toks})
+    _, cache = model.prefill(params, {"tokens": toks[:, :-1]})
+
+    def widen(z, c):
+        if c.ndim < 4 or z.shape == c.shape:
+            return c
+        return z.at[:, :, :c.shape[2]].set(c)
+    cache = jax.tree_util.tree_map(
+        widen, model.init_cache(toks.shape[0], max_seq), cache)
+    lengths = jnp.full((toks.shape[0],), toks.shape[1] - 1, jnp.int32)
+    got, _ = jax.jit(model.decode_step)(params, cache, toks[:, -1], lengths)
+    return np.asarray(got), np.asarray(want)
+
+
+def _scan_carries(model, params, cache, tokens, lengths):
+    """The number of leaves each layer scan of the decode step carries."""
+    jaxpr = jax.make_jaxpr(model.decode_step)(params, cache, tokens, lengths)
+    return [e.params["num_carry"] for e in jaxpr.eqns
+            if e.primitive.name == "scan"]
+
+
+@pytest.mark.parametrize("arch", ["deepseek-7b", "llama4-maverick-400b-a17b"])
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+def test_decode_writes_one_position_into_the_stacked_cache(arch, scan,
+                                                           backend, rng):
+    """Dense and MoE stacks of attention blocks carry the stacked cache
+    through the layers: the step returns it changed only at
+    ``[layer, b, lengths[b]]`` of every layer, and its logits are those of
+    a prefill over the same tokens."""
+    from repro.kernels import ops
+    cfg = _fp32(arch, scan_layers=scan)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, S = 2, 16
+    cache = jax.tree_util.tree_map(
+        lambda s: jnp.asarray(rng.normal(size=s.shape), s.dtype),
+        model.cache_spec(B, S))
+    lengths = jnp.array([3, 9], jnp.int32)
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (B,)), jnp.int32)
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 9)), jnp.int32)
+    with ops.backend(backend):
+        _, new = jax.jit(model.decode_step)(params, cache, tokens, lengths)
+        got, want = _decode_after_prefill(model, params, toks, S)
+    written = np.zeros((cfg.n_layers, B, S), bool)
+    written[:, np.arange(B), np.asarray(lengths)] = True
+    for name in ("k", "v"):
+        changed = np.any(np.asarray(new[name]) != np.asarray(cache[name]),
+                         axis=(-2, -1))
+        np.testing.assert_array_equal(changed, written)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    carries = _scan_carries(model, params, cache, tokens, lengths)
+    assert carries == ([1 + len(cache)] if scan else [])
+
+
+@pytest.mark.parametrize("arch", ["deepseek-v2-236b", "mamba2-780m",
+                                  "recurrentgemma-9b"])
+def test_other_caches_keep_the_layer_scan(arch, rng):
+    """MLA, SSD and hybrid (windowed ring) caches still go through the scan
+    as ``xs`` and ``ys``, and still decode as a prefill over the same
+    tokens computes."""
+    cfg = _fp32(arch)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, S = 2, 16
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 7)), jnp.int32)
+    got, want = _decode_after_prefill(model, params, toks, S)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    lengths = jnp.zeros((B,), jnp.int32)
+    carries = _scan_carries(model, params, model.init_cache(B, S),
+                            lengths, lengths)
+    assert carries and all(c == 1 for c in carries)

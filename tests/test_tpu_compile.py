@@ -9,17 +9,23 @@ times are not checked here.
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.configs.shapes import ShapeConfig
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.ssd_scan import ssd_scan
+from repro.launch import steps as steplib
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 
 HBM_BYTES = 16 * 2 ** 30           # one v5e chip
@@ -120,3 +126,41 @@ def test_granite_decode_step_compiles_for_v5e(one_chip):
         compiled = _compile(model.decode_step, params, cache, tokens, tokens)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes < HBM_BYTES
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                          r"([\w\-]+)\(")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def test_serve_step_writes_the_stacked_cache_in_place(one_chip):
+    """The serve step at deepseek-7b widths (3 layers, 16 rows x 512
+    positions, the cache donated) neither slices a layer's K/V cache out
+    of the stack and writes it back, nor copies the stack: its only
+    instructions that yield the stack are the in-place ``kv_write``
+    scatters, and its temporaries stay under one layer's K cache."""
+    L, B, S = 3, 16, 512
+    cfg = dataclasses.replace(configs.get_config("deepseek-7b"), n_layers=L)
+    bundle = steplib.make_serve_step(cfg, ShapeConfig("d", S, B, "decode"),
+                                     make_local_mesh())
+    args = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype), bundle.input_specs)
+    with ops.backend("pallas"):
+        compiled = jax.jit(bundle.fn, donate_argnums=bundle.donate_argnums) \
+            .lower(*args).compile()
+    K, D = cfg.n_kv_heads, cfg.resolved_head_dim
+    layer, stack = f"bf16[{B},{S},{K},{D}]", f"bf16[{L},{B},{S},{K},{D}]"
+    moves, writes = [], 0
+    for line in compiled.as_text().splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(2) not in (layer, stack):
+            continue
+        name, shape, opcode = m.groups()
+        if opcode in _MOVES or (opcode == "fusion"
+                                and any(w in name for w in _MOVES)):
+            moves.append(f"{name} = {shape} {opcode}")
+        writes += opcode == "fusion" and "/kv_write/" in line
+    assert not moves, moves
+    assert writes == 2                              # K and V, in the scan
+    one_layer_k = B * S * K * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_k
