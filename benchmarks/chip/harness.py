@@ -22,7 +22,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import os
@@ -91,11 +90,7 @@ class Run:
 
 
 def load_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    sp = importlib.util.spec_from_file_location(f"_metric_{name}", path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    return spec.load(HERE / "metrics" / f"{name}.py").read
 
 
 def enable_compile_cache(root: Path) -> str:

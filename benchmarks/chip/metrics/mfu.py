@@ -13,7 +13,8 @@ def read(run):
     reqs = run.served
     if not reqs or run.in_service_s <= 0:
         return None
-    flops = sum(work.model_flops(run.cfg, r["hist"], r["fed"], len(r["out"]))
+    flops = sum(work.model_flops(run.cfg, r["hist"], r["fed"], len(r["out"]),
+                                 r.get("prefilled", 0))
                 for r in reqs)
     return 100.0 * flops / (run.in_service_s * run.chips
                             * run.peaks["flops_bf16"])

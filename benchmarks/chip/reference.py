@@ -1,12 +1,10 @@
-"""Plain float32 reference of a dense decoder, and its fp8 control.
+"""Plain float32 reference of a decoder, and its fp8 control.
 
-Written from the published description of the llama architecture
-(RMSNorm, rotary embedding on split halves, grouped-query causal attention
-scaled by head_dim ** -0.5, SwiGLU MLP, tied or untied head).  It imports
-nothing of
-the program: weights come from ``weights`` and the seed, one layer at a
-time, and every matrix product runs in float32 at
-``jax.default_matmul_precision("highest")``.
+The layers and the head are the architecture's own, written from its
+published description in ``families/<model_type>.py``; this module runs
+them.  It imports nothing of the program: weights come from ``weights``
+and the seed, one layer at a time, and every matrix product runs in
+float32 at ``jax.default_matmul_precision("highest")``.
 
 ``gaps`` runs the reference over whole sessions as sent and served
 (padded to one length, so one compile serves every run) and returns, for
@@ -20,12 +18,13 @@ float32 logits, of the token that fp8 puts first.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights
+from . import spec, weights
 
 F8_MAX = 448.0
 
@@ -37,83 +36,54 @@ def _q8(x, axis=None):
     return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, pos, theta):
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos[..., None].astype(jnp.float32) * freqs           # (B,S,half)
-    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _layer(cfg, w, x, low):
-    q8w = (lambda a: _q8(a)) if low else (lambda a: a)
-    q8a = (lambda a: _q8(a, -1)) if low else (lambda a: a)
-    eps = cfg["rms_norm_eps"]
-    B, S, _ = x.shape
-    g = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
-    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    m, p = w["mixer"], w["mlp"]
-    h = q8a(_rmsnorm(x, m["ln"], eps))
-    q = _rope(jnp.einsum("bsd,dhk->bshk", h, q8w(m["wq"])), pos,
-              cfg["rope_theta"])
-    k = _rope(jnp.einsum("bsd,dhk->bshk", h, q8w(m["wk"])), pos,
-              cfg["rope_theta"])
-    v = jnp.einsum("bsd,dhk->bshk", h, q8w(m["wv"]))
-    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    if low:
-        q, k, v = _q8(q, -1), _q8(k, -1), _q8(v, -1)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * cfg["head_dim"] ** -0.5
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-    x = x + jnp.einsum("bqhd,hdm->bqm", q8a(o.reshape(B, S, -1)).reshape(
-        o.shape), q8w(m["wo"]))
-    h = q8a(_rmsnorm(x, p["ln"], eps))
-    a = q8a(jax.nn.silu(h @ q8w(p["wi_gate"])) * (h @ q8w(p["wi_up"])))
-    return x + a @ q8w(p["wo_mlp"])
-
-
-def _logits(cfg, e, x, low):
-    h = _rmsnorm(x, e["final_norm"], cfg["rms_norm_eps"])
-    w = e["tok_embed"].T if cfg["tie_word_embeddings"] else e["unembed"]
-    if low:
-        h, w = _q8(h, -1), _q8(w)
-    return h @ w
-
-
 def _f32(tree):
     return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
 
+def _same(a):
+    return a
+
+
+def _q8a(a):
+    return _q8(a, -1)
+
+
 @functools.lru_cache(maxsize=None)
-def _programs(frozen: tuple, low: bool):
-    cfg = dict(frozen)
+def _programs(frozen: str, low: bool):
+    """(embed, {stack key: one layer}, gap), jitted."""
+    cfg = json.loads(frozen)
+    fam = spec.family(cfg)
+    q8w, q8a = (_q8, _q8a) if low else (_same, _same)
 
     def embed(e, tokens):
         return _f32(e)["tok_embed"][tokens]
 
-    def layer(w, x):
-        return _layer(cfg, _f32(w), x, low)
+    def stack(key):
+        return lambda w, x: fam.layer(cfg, key, _f32(w), x, q8w, q8a)
 
     def gap(e, x, x_low, targets):
         """Per position: (gap of the served token, gap of fp8's first)."""
         e = _f32(e)
-        ref = _logits(cfg, e, x, False)
+        ref = fam.logits(cfg, e, x, _same, _same)
         best = ref.max(-1)
         safe = jnp.maximum(targets, 0)
         served = best - jnp.take_along_axis(ref, safe[..., None], -1)[..., 0]
         if x_low is None:
             return served, served
-        pick = _logits(cfg, e, x_low, True).argmax(-1)
+        pick = fam.logits(cfg, e, x_low, _q8, _q8a).argmax(-1)
         low_gap = best - jnp.take_along_axis(ref, pick[..., None], -1)[..., 0]
         return served, low_gap
 
-    return jax.jit(embed), jax.jit(layer), jax.jit(gap)
+    layers = {s.key: jax.jit(stack(s.key)) for s in fam.stacks(cfg)}
+    return jax.jit(embed), layers, jax.jit(gap)
+
+
+def _by_rows(layer, w, x, rows):
+    """``layer`` over ``x`` in blocks of ``rows`` rows (None: all)."""
+    if rows is None or rows >= x.shape[0]:
+        return layer(w, x)
+    return jnp.concatenate([layer(w, x[b:b + rows])
+                            for b in range(0, x.shape[0], rows)])
 
 
 def gaps(cfg: dict, seed: int, tokens: np.ndarray, targets: np.ndarray,
@@ -125,17 +95,18 @@ def gaps(cfg: dict, seed: int, tokens: np.ndarray, targets: np.ndarray,
     token was served."""
     frozen = weights._freeze(cfg)
     with jax.default_matmul_precision("highest"):
-        embed, layer, _ = _programs(frozen, False)
-        low_layer = _programs(frozen, True)[1]
+        embed, layers, _ = _programs(frozen, False)
+        low_layers = _programs(frozen, True)[1]
         gap = _programs(frozen, control)[2]
+        rows = spec.family(cfg).reference_rows(cfg)
         e = weights.embed(cfg, seed)
         x = embed(e, jnp.asarray(tokens, jnp.int32))
         x_low = x if control else None
-        for i in range(cfg["num_hidden_layers"]):
+        for i, kind in enumerate(weights.kinds(cfg)):
             w = weights.layer(cfg, seed, i)
-            x = layer(w, x)
+            x = _by_rows(layers[kind], w, x, rows)
             if control:
-                x_low = low_layer(w, x_low)
+                x_low = _by_rows(low_layers[kind], w, x_low, rows)
             del w
         served, low = [], []
         for b in range(0, tokens.shape[0], rows_per_block):

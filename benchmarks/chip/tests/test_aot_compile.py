@@ -105,13 +105,16 @@ def test_weights_and_reference_compile(one_chip, name):
     mem = make.lower(words).compile().memory_analysis()
     assert mem.output_size_in_bytes < HBM_BYTES
     assert mem.temp_size_in_bytes < 2 ** 30          # made in one fused pass
-    layer = _on(one_chip, jax.eval_shape(lambda: weights.layer(cfg, 1, 0)))
     x = jax.ShapeDtypeStruct((mix["compare_requests"],
                               mix["prompt"] + mix["answer"],
                               cfg["hidden_size"]), jnp.float32,
                              sharding=one_chip)
     with jax.default_matmul_precision("highest"):
-        for low in (False, True):
-            fn = reference._programs(weights._freeze(cfg), low)[1]
-            mem = fn.lower(layer, x).compile().memory_analysis()
-            assert mem.temp_size_in_bytes < HBM_BYTES // 4
+        for stack, first in weights.layout(cfg):
+            layer = _on(one_chip, jax.eval_shape(
+                lambda: weights.layer(cfg, 1, first)))
+            for low in (False, True):
+                fn = reference._programs(weights._freeze(cfg),
+                                         low)[1][stack.key]
+                mem = fn.lower(layer, x).compile().memory_analysis()
+                assert mem.temp_size_in_bytes < HBM_BYTES // 4

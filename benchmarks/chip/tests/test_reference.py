@@ -27,8 +27,9 @@ def test_layer_params_match_the_program_count():
         mc = spec.model_config(cfg)
         emb = cfg["vocab_size"] * cfg["hidden_size"] * (
             1 if cfg["tie_word_embeddings"] else 2)
+        fam = spec.family(cfg)
         assert ModelConfig.param_count(mc) - emb == \
-            cfg["num_hidden_layers"] * work.layer_matmul_params(cfg)
+            cfg["num_hidden_layers"] * fam.layer_matmul_params(cfg)
 
 
 def test_kv_bytes_match_the_program_cache():
@@ -37,15 +38,15 @@ def test_kv_bytes_match_the_program_cache():
     for name, per_pos in (("deepseek-7b", 240 * 1024),):
         cfg = spec.load_json(spec.HERE / "configs" / f"{name}.json")
         model = build_model(spec.model_config(cfg))
-        assert work.kv_bytes_per_position(cfg) == per_pos == \
+        assert spec.family(cfg).cache_bytes(cfg) == per_pos == \
             kv_cache.session_cache_bytes(model, 64) // 64
 
 
 def test_attention_counts_by_hand():
     cfg = tiny_cfg()
-    kvb = work.kv_bytes_per_position(cfg)
+    kvb = spec.family(cfg).cache_bytes(cfg)
     # history 3, two fed positions: contexts 4 and 5
-    assert work.attention_flops(cfg, 3, 2) == 4 * 2 * 4 * 16 * 9
+    assert work.attention_flops(cfg, "decode", 3, 2) == 4 * 2 * 4 * 16 * 9
     # history 3 + prompt 2 read once; decode inputs read 6 and 7
     assert work.attention_bytes(cfg, 3, 2, 2) == kvb * (5 + 6 + 7)
     # no prompt pass: only the decode inputs' contexts, 4 and 5
@@ -53,7 +54,7 @@ def test_attention_counts_by_hand():
     t = dict(hist=3, prompt=[1, 2], fed=4)
     peaks = {"flops_bf16": 1.0, "hbm_bytes_per_s": 1e30}
     assert work.least_decode_attention_seconds(cfg, [t], peaks) == \
-        work.attention_flops(cfg, 3, 4)
+        work.attention_flops(cfg, "decode", 3, 4)
     assert work.least_prefill_attention_seconds(cfg, [t], peaks) == 0
 
 
@@ -61,7 +62,7 @@ def test_prefill_and_decode_split_by_hand():
     """A batch request: 3 prompt positions in one prefill pass, then 2
     decode inputs at contexts 4 and 5."""
     cfg = tiny_cfg()
-    kvb = work.kv_bytes_per_position(cfg)
+    kvb = spec.family(cfg).cache_bytes(cfg)
     r = dict(hist=0, prompt=[7, 8, 9], fed=5, prefilled=3)
     flops = {"flops_bf16": 1.0, "hbm_bytes_per_s": 1e30}
     nbytes = {"flops_bf16": 1e30, "hbm_bytes_per_s": 1.0}

@@ -11,37 +11,37 @@ drops its scale shows (standard deviation 0.1).
 from __future__ import annotations
 
 import functools
+import json
 import math
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from . import spec
+
 NORM_SPREAD = 0.1
 
 
-def _layer_leaves(cfg: dict):
-    """(group, name, shape, std, is_norm) of one decoder layer."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, k, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                cfg["head_dim"])
-    return (("mixer", "ln", (d,), NORM_SPREAD, True),
-            ("mixer", "wq", (d, h, dh), d ** -0.5, False),
-            ("mixer", "wk", (d, k, dh), d ** -0.5, False),
-            ("mixer", "wv", (d, k, dh), d ** -0.5, False),
-            ("mixer", "wo", (h, dh, d), (h * dh) ** -0.5, False),
-            ("mlp", "ln", (d,), NORM_SPREAD, True),
-            ("mlp", "wi_gate", (d, f), d ** -0.5, False),
-            ("mlp", "wi_up", (d, f), d ** -0.5, False),
-            ("mlp", "wo_mlp", (f, d), f ** -0.5, False))
+class Leaf(NamedTuple):
+    """One tensor of a layer (under ``group``) or of the embedding (group
+    None), uniform with standard deviation ``std`` (plus 1 for a norm
+    scale).  A leaf with ``slices`` holds, along axis 0, the published
+    indices it lists, each drawn from a key of its own: a share of a
+    layer's experts then holds the same values as the uncut layer."""
+    group: Optional[str]
+    name: str
+    shape: Tuple[int, ...]
+    std: float
+    is_norm: bool = False
+    slices: Optional[Sequence[int]] = None
 
 
-def _embed_leaves(cfg: dict):
-    d, v = cfg["hidden_size"], cfg["vocab_size"]
-    out = [("tok_embed", (v, d), d ** -0.5, False)]
-    if not cfg["tie_word_embeddings"]:
-        out.append(("unembed", (d, v), d ** -0.5, False))
-    out.append(("final_norm", (d,), NORM_SPREAD, True))
-    return out
+class Stack(NamedTuple):
+    """``count`` layers of one kind under the parameter tree's ``key``."""
+    key: str
+    count: int
+    leaves: Sequence[Leaf]
 
 
 def seed_words(seed: int) -> jnp.ndarray:
@@ -65,40 +65,71 @@ def _uniform(key, shape, std, is_norm):
     return x.astype(jnp.bfloat16)
 
 
-def _layer(cfg, words, i):
-    key = jax.random.fold_in(jax.random.fold_in(_base(words), 1), i)
-    out = {"mixer": {}, "mlp": {}}
-    for j, (grp, name, shape, std, is_norm) in enumerate(_layer_leaves(cfg)):
-        out[grp][name] = _uniform(jax.random.fold_in(key, j), shape, std,
-                                  is_norm)
+def _draw(key, leaf: Leaf):
+    if leaf.slices is None:
+        return _uniform(key, leaf.shape, leaf.std, leaf.is_norm)
+    return jax.vmap(lambda e: _uniform(jax.random.fold_in(key, e),
+                                       leaf.shape[1:], leaf.std,
+                                       leaf.is_norm))(
+        jnp.asarray(leaf.slices, jnp.uint32))
+
+
+def _tree(key, leaves):
+    """Leaf ``j`` from ``fold_in(key, j)``."""
+    out = {}
+    for j, leaf in enumerate(leaves):
+        into = out if leaf.group is None else out.setdefault(leaf.group, {})
+        into[leaf.name] = _draw(jax.random.fold_in(key, j), leaf)
     return out
 
 
-def _embed(cfg, words):
-    key = jax.random.fold_in(_base(words), 0)
-    return {name: _uniform(jax.random.fold_in(key, j), shape, std, is_norm)
-            for j, (name, shape, std, is_norm) in
-            enumerate(_embed_leaves(cfg))}
+def _layer(words, i, leaves):
+    """Layer ``i`` of the model, whichever stack holds it."""
+    key = jax.random.fold_in(jax.random.fold_in(_base(words), 1), i)
+    return _tree(key, leaves)
 
 
-def _freeze(cfg: dict) -> tuple:
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, bool, str))))
+def _freeze(cfg: dict) -> str:
+    # the whole file, nested groups (such as a rope_scaling) included
+    return json.dumps(cfg, sort_keys=True)
+
+
+def layout(cfg: dict):
+    """(stack, index in the model of its first layer), in model order."""
+    out, first = [], 0
+    for s in spec.family(cfg).stacks(cfg):
+        out.append((s, first))
+        first += s.count
+    return out
+
+
+def kinds(cfg: dict):
+    """The stack key of every layer, in model order."""
+    return [s.key for s, _ in layout(cfg) for _ in range(s.count)]
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted(frozen: tuple):
-    cfg = dict(frozen)
-    n = cfg["num_hidden_layers"]
+def _jitted(frozen: str):
+    """(make, {stack key: one layer}, embed), jitted."""
+    cfg = json.loads(frozen)
+    stacks = layout(cfg)
+    embed_leaves = spec.family(cfg).embed_leaves(cfg)
+
+    def embed(words):
+        return _tree(jax.random.fold_in(_base(words), 0), embed_leaves)
+
+    def stack(leaves):
+        return lambda words, i: _layer(words, i, leaves)
 
     def make(words):
-        layers = jax.vmap(lambda i: _layer(cfg, words, i))(
-            jnp.arange(n, dtype=jnp.uint32))
-        return {"embed": _embed(cfg, words), "layers": layers}
+        out = {"embed": embed(words)}
+        for s, first in stacks:
+            out[s.key] = jax.vmap(stack(s.leaves), (None, 0))(
+                words, jnp.arange(first, first + s.count, dtype=jnp.uint32))
+        return out
 
-    return (jax.jit(make),
-            jax.jit(lambda words, i: _layer(cfg, words, i)),
-            jax.jit(lambda words: _embed(cfg, words)))
+    layer = {s.key: jax.jit(stack(s.leaves)) for s, _ in stacks}
+    return jax.jit(make), layer, jax.jit(embed)
 
 
 def make(cfg: dict, seed: int, device=None):
@@ -111,8 +142,10 @@ def make(cfg: dict, seed: int, device=None):
 
 
 def layer(cfg: dict, seed: int, i: int):
-    """Layer ``i`` alone, bit for bit as ``make`` holds it."""
-    return _jitted(_freeze(cfg))[1](seed_words(seed), jnp.uint32(i))
+    """Layer ``i`` of the model alone, bit for bit as ``make`` holds it in
+    its stack."""
+    return _jitted(_freeze(cfg))[1][kinds(cfg)[i]](seed_words(seed),
+                                                   jnp.uint32(i))
 
 
 def embed(cfg: dict, seed: int):

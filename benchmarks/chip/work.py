@@ -4,27 +4,18 @@ Counted from what each turn asks of the model, whatever the
 implementation does: a later PR that skips work or batches it cannot
 raise its own share of a peak by changing these counts.
 
-A request (a turn, on a session that already holds ``hist`` positions)
-feeds ``fed`` positions (its prompt, then its decode inputs) and produces
-``gen`` tokens.  Position ``hist + j`` attends to ``hist + j + 1``
-positions.  Of the fed positions the first ``prefilled`` (0 where the
-request has none) go through one parallel prefill pass, the flash
-attention's work; the rest go through the decode attention.
+What a position costs is the architecture's (``families/<model_type>.py``);
+this module sums it over requests.  A request (a turn, on a session that
+already holds ``hist`` positions) feeds ``fed`` positions (its prompt, then
+its decode inputs) and produces ``gen`` tokens.  Position ``hist + j``
+attends to ``hist + j + 1`` positions.  Of the fed positions the first
+``prefilled`` (0 where the request has none) go through one parallel
+prefill pass, the flash attention's work; the rest go through the decode
+attention.
 """
 from __future__ import annotations
 
-
-def layer_matmul_params(cfg: dict) -> int:
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, k = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = cfg["head_dim"]
-    return d * h * dh + 2 * d * k * dh + h * dh * d + 3 * d * f
-
-
-def kv_bytes_per_position(cfg: dict) -> int:
-    """K and V of one position over every layer, bf16."""
-    return (cfg["num_hidden_layers"] * 2 * cfg["num_key_value_heads"]
-            * cfg["head_dim"] * 2)
+from . import spec
 
 
 def _context_sum(hist: int, fed: int) -> int:
@@ -32,10 +23,12 @@ def _context_sum(hist: int, fed: int) -> int:
     return fed * hist + fed * (fed + 1) // 2
 
 
-def attention_flops(cfg: dict, hist: int, fed: int) -> float:
-    """QK^T and PV: 4 * heads * head_dim per attended position, per layer."""
-    return (4.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"]
-            * cfg["head_dim"] * _context_sum(hist, fed))
+def attention_flops(cfg: dict, path: str, hist: int, fed: int) -> float:
+    """Attention of ``fed`` positions after ``hist`` on ``path``
+    (``"prefill"`` or ``"decode"``): the family's FLOPs per attended
+    position, over every layer, times the positions attended."""
+    return (spec.family(cfg).attention_flops(cfg, path)
+            * _context_sum(hist, fed))
 
 
 def attention_bytes(cfg: dict, hist: int, prompt: int, dec: int) -> float:
@@ -44,24 +37,21 @@ def attention_bytes(cfg: dict, hist: int, prompt: int, dec: int) -> float:
     (decoding is sequential)."""
     first = hist + prompt
     ctx = dec * first + dec * (dec + 1) // 2
-    return float(kv_bytes_per_position(cfg)
+    return float(spec.family(cfg).cache_bytes(cfg)
                  * ((first if prompt else 0) + ctx))
 
 
-def prefill_bytes(cfg: dict, n: int) -> float:
-    """A prefill pass over ``n`` positions with no history reads each
-    position's queries, keys and values once and writes its output, bf16."""
-    per = (2 * cfg["num_attention_heads"] + 2 * cfg["num_key_value_heads"]) \
-        * cfg["head_dim"] * 2
-    return float(cfg["num_hidden_layers"] * per * n)
-
-
-def model_flops(cfg: dict, hist: int, fed: int, gen: int) -> float:
-    """2 x matmul parameters per fed position, the LM head for each
-    generated token, and attention over each position's real context."""
-    head = 2.0 * cfg["hidden_size"] * cfg["vocab_size"] * gen
-    return (2.0 * layer_matmul_params(cfg) * cfg["num_hidden_layers"] * fed
-            + head + attention_flops(cfg, hist, fed))
+def model_flops(cfg: dict, hist: int, fed: int, gen: int,
+                prefilled: int) -> float:
+    """The layers' matmuls for each fed position, the LM head for each
+    generated token, and attention over each position's real context, the
+    first ``prefilled`` fed positions on the prefill path and the rest on
+    the decode path."""
+    fam = spec.family(cfg)
+    return (fam.matmul_flops(cfg) * fed + fam.head_flops(cfg) * gen
+            + attention_flops(cfg, "prefill", hist, prefilled)
+            + attention_flops(cfg, "decode", hist + prefilled,
+                              fed - prefilled))
 
 
 def _least(flops: float, nbytes: float, peaks: dict) -> float:
@@ -72,21 +62,23 @@ def least_decode_attention_seconds(cfg: dict, reqs, peaks: dict) -> float:
     """The least time of the attention that goes through the decode
     attention, summed over ``reqs`` (dicts with ``hist``, ``prompt`` (the
     tokens), ``fed`` and optionally ``prefilled``): the larger of its FLOPs
-    over peak and its K/V bytes over HBM bandwidth."""
+    over peak and its cache bytes over HBM bandwidth."""
     flops = nbytes = 0.0
     for r in reqs:
         pre = r.get("prefilled", 0)
         hist, fed = r["hist"] + pre, r["fed"] - pre
         prompt = max(len(r["prompt"]) - pre, 0)
-        flops += attention_flops(cfg, hist, fed)
+        flops += attention_flops(cfg, "decode", hist, fed)
         nbytes += attention_bytes(cfg, hist, prompt, fed - prompt)
     return _least(flops, nbytes, peaks)
 
 
 def least_prefill_attention_seconds(cfg: dict, reqs, peaks: dict) -> float:
     """The same for the prefill pass of each request that has one (no
-    history: causal attention over its ``prefilled`` positions)."""
+    history: causal attention over its ``prefilled`` positions, each
+    position's queries, keys, values and output read or written once)."""
     pre = [r["prefilled"] for r in reqs if r.get("prefilled")]
-    flops = sum(attention_flops(cfg, 0, n) for n in pre)
-    nbytes = sum(prefill_bytes(cfg, n) for n in pre)
+    per = spec.family(cfg).prefill_bytes(cfg)
+    flops = sum(attention_flops(cfg, "prefill", 0, n) for n in pre)
+    nbytes = sum(float(per * n) for n in pre)
     return _least(flops, nbytes, peaks)
